@@ -169,22 +169,20 @@ def _multi_k_record(rows, full: bool = False):
 
 
 def _distributed_rounds_record(rows, n_dev=4, log2_n=20):
-    """Psum-round counts from the forced-host-device subprocess worker;
-    returns None (and keeps the bench green) if the worker can't run."""
+    """Psum-round counts from the forced-host-device subprocess worker.
+
+    The worker counts rounds on virtual CPU devices, so it is pinned to
+    the CPU: this process may already hold an accelerator, which a child
+    could not open.  A worker that fails or times out fails the bench."""
     worker = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                           "_dist_rounds_worker.py")
     env = dict(os.environ)
     root = os.path.dirname(os.path.dirname(os.path.abspath(worker)))
     env["PYTHONPATH"] = os.path.join(root, "src")
-    try:
-        # bounded: a slow/overloaded runner skips the record (visibly, as
-        # "distributed": null) instead of eating the CI budget
-        out = subprocess.run(
-            [sys.executable, worker, str(n_dev), str(log2_n)],
-            capture_output=True, text=True, env=env, timeout=600)
-    except Exception as exc:  # pragma: no cover - environment-dependent
-        print(f"distributed rounds worker skipped: {exc}")
-        return None
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run(
+        [sys.executable, worker, str(n_dev), str(log2_n)],
+        capture_output=True, text=True, env=env, timeout=600)
     for line in out.stdout.splitlines():
         if line.startswith("DIST_ROUNDS_JSON "):
             rec = json.loads(line[len("DIST_ROUNDS_JSON "):])
@@ -194,8 +192,8 @@ def _distributed_rounds_record(rows, n_dev=4, log2_n=20):
                 f"binned={rec['rounds_binned']} weighted_polish="
                 f"{rec['rounds_binned_polish_weighted']}"))
             return rec
-    print("distributed rounds worker failed:\n", out.stdout, out.stderr)
-    return None
+    raise RuntimeError(f"distributed rounds worker failed (rc="
+                       f"{out.returncode}):\n{out.stdout}\n{out.stderr}")
 
 
 def _warm_start_record(rows, full: bool = False):

@@ -38,6 +38,10 @@ def main() -> None:
     import jax
     jax.config.update("jax_enable_x64", True)
 
+    sys.path.insert(0, os.path.join(ROOT, "src"))
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
+
     from benchmarks import (
         batched_selection_bench,
         clip_bench,

@@ -1,11 +1,5 @@
-"""Version-compat shims for jax APIs that moved between releases.
-
-The codebase targets current jax (``jax.shard_map``, ``jax.sharding.AxisType``,
-``check_vma``); CI and some containers carry older releases where shard_map
-still lives in ``jax.experimental`` (flag ``check_rep``) and meshes have no
-axis types.  Keep every cross-version touchpoint here so the rest of the code
-reads as if written for one jax.
-"""
+"""Mesh and shard_map entry points shared by the selection, model and test
+code, with the repository's defaults in one place."""
 from __future__ import annotations
 
 import functools
@@ -14,49 +8,27 @@ import jax
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with Auto axis types where the concept exists."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def shard_map(f=None, *, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` across jax versions.
-
-    ``check=False`` disables the static replication/varying-axis analysis
-    (``check_vma`` on current jax, ``check_rep`` before it) — the distributed
-    selection results are semantically replicated (built from psum/all_gather
-    outputs) but the analysis cannot prove it.
+    """``jax.shard_map``; ``check=False`` disables the static varying-axis
+    analysis (``check_vma``) — the distributed selection results are
+    semantically replicated (built from psum/all_gather outputs) but the
+    analysis cannot prove it.
     """
     if f is None:
         return functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check=check)
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def scan_in_shard_map(body, init, n: int):
-    """``lax.scan(body, init, jnp.arange(n))`` usable INSIDE a shard_map
-    that gets differentiated.
-
-    The pre-0.5 shard_map cannot transpose a ``lax.scan`` living in its
-    body (scalar residuals leak into the transposed out-specs); since the
-    trip count is static at every call site, fall back to a Python unroll
-    there.  Current jax keeps the real scan (O(1) jaxpr size).
-    """
+    """``lax.scan(body, init, jnp.arange(n))``, returning the carry."""
     import jax.numpy as jnp
 
-    if hasattr(jax, "shard_map"):
-        carry, _ = jax.lax.scan(body, init, jnp.arange(n))
-        return carry
-    carry = init
-    for i in range(n):
-        carry, _ = body(carry, jnp.asarray(i))
+    carry, _ = jax.lax.scan(body, init, jnp.arange(n))
     return carry

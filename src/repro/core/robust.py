@@ -37,6 +37,12 @@ import jax.numpy as jnp
 from repro.core import distributed, selection
 from repro.core.objective import fg_from_partials
 
+# Residuals and least-squares refits feed EXACT selections, so every
+# matmul here runs at full f32 precision: the TPU default rounds f32
+# operands to bf16, which would put ~1e-3 relative error into every residual
+# (a no-op on CPU).
+_mm = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
 
 # ---------------------------------------------------------------------------
 # LMS / LTS objectives
@@ -44,7 +50,7 @@ from repro.core.objective import fg_from_partials
 
 
 def residuals(theta, X, y):
-    return X @ theta - y
+    return _mm(X, theta) - y
 
 
 def lms_objective(theta, X, y, **kw):
@@ -105,8 +111,8 @@ def _elemental_thetas(key, X, y, n_starts):
         A = X[idx]
         b = y[idx]
         # ridge-regularized solve for degenerate subsets
-        G = A.T @ A + 1e-8 * jnp.eye(p, dtype=X.dtype)
-        return jnp.linalg.solve(G, A.T @ b)
+        G = _mm(A.T, A) + 1e-8 * jnp.eye(p, dtype=X.dtype)
+        return jnp.linalg.solve(G, _mm(A.T, b))
 
     return jax.vmap(solve_one)(keys)
 
@@ -155,8 +161,8 @@ def _nan_prior(shape, pdt) -> selection.Prior:
 
 def _weighted_ls(X, y, w):
     Xw = X * w[:, None]
-    G = X.T @ Xw + 1e-8 * jnp.eye(X.shape[1], dtype=X.dtype)
-    return jnp.linalg.solve(G, Xw.T @ y)
+    G = _mm(X.T, Xw) + 1e-8 * jnp.eye(X.shape[1], dtype=X.dtype)
+    return jnp.linalg.solve(G, _mm(Xw.T, y))
 
 
 def _weighted_ls_rows(X, y, W):
@@ -198,7 +204,7 @@ def lts_fit(key, X, y, *, h: Optional[int] = None, n_starts: int = 64,
 
     def c_step(carry, _):
         thetas, pr = carry
-        R = thetas @ X.T - y[None, :]          # (n_starts, n) residuals
+        R = _mm(thetas, X.T) - y[None, :]      # (n_starts, n) residuals
         W, res = _lts_weights_rows(R, hh, method,
                                    prior=pr if warm else None)
         pr_n = _carry_prior(res, (n_starts,), pdt)
@@ -207,7 +213,8 @@ def lts_fit(key, X, y, *, h: Optional[int] = None, n_starts: int = 64,
     (thetas, prf), sweeps = jax.lax.scan(
         c_step, (thetas0, _nan_prior((n_starts,), pdt)), None,
         length=c_steps)
-    objs = lts_objective_rows(thetas @ X.T - y[None, :], hh, method=method,
+    objs = lts_objective_rows(_mm(thetas, X.T) - y[None, :], hh,
+                              method=method,
                               prior=prf if warm else None)
     best = jnp.argmin(objs)
     theta = thetas[best]
@@ -231,7 +238,7 @@ def lms_fit(key, X, y, *, n_starts: int = 256,
     """
     n = X.shape[0]
     thetas = _elemental_thetas(key, X, y, n_starts)
-    R2 = (thetas @ X.T - y[None, :]) ** 2      # (n_starts, n)
+    R2 = (_mm(thetas, X.T) - y[None, :]) ** 2  # (n_starts, n)
     objs = selection.select_rows(R2, (n + 1) // 2, method=method).value
     best = jnp.argmin(objs)
     theta = thetas[best]
@@ -416,7 +423,7 @@ def irls_fit(X, y, *, loss: str = "huber", c: Optional[float] = None,
 
     def step(carry, _):
         theta, w, pr = carry
-        r = y - X @ theta
+        r = y - _mm(X, theta)
         res = selection.weighted_median(jnp.abs(r), w, method=method,
                                         prior=pr if warm else None)
         mad = res.value
@@ -433,7 +440,7 @@ def irls_fit(X, y, *, loss: str = "huber", c: Optional[float] = None,
     # re-evaluate scale/weights/objective AT the returned theta (the scan
     # carries them one iterate stale: sigma was measured on the pre-refit
     # residuals, which would make objectives incomparable across iters)
-    r = y - X @ theta
+    r = y - _mm(X, theta)
     mad = selection.weighted_median(jnp.abs(r), w, method=method,
                                     prior=prf if warm else None).value
     scale = jnp.maximum(1.4826 * mad, min_scale)
@@ -454,7 +461,7 @@ def knn_predict(train_x, train_y, query_x, k: int, *, classify: bool = False,
     # squared euclidean distances via ||a-b||^2 expansion (one matmul)
     d2 = (
         jnp.sum(query_x**2, -1, keepdims=True)
-        - 2.0 * query_x @ train_x.T
+        - 2.0 * _mm(query_x, train_x.T)
         + jnp.sum(train_x**2, -1)[None, :]
     )
 
@@ -467,9 +474,9 @@ def knn_predict(train_x, train_y, query_x, k: int, *, classify: bool = False,
     w = lt + eq * frac  # sums to exactly k per query
     if classify:
         onehot = jax.nn.one_hot(train_y, n_classes, dtype=d2.dtype)
-        votes = w @ onehot
+        votes = _mm(w, onehot)
         return jnp.argmax(votes, -1)
-    return (w @ train_y) / k
+    return _mm(w, train_y) / k
 
 
 # ---------------------------------------------------------------------------

@@ -57,8 +57,8 @@ Each iteration costs exactly one fused pass over the data — the paper's
 ``maxit + O(1)`` parallel reductions — regardless of how many problems ride
 in the batch; ``binned`` needs ~3 such passes where ``cp`` needs ~15.
 ``method=None`` (the default) resolves to ``binned`` for
-``n >= BINNED_MIN_N`` on EVERY backend: the Pallas kernels bin in-register
-(a sweep costs the same HBM traffic as an FG pass), and the jnp path's
+``n >= BINNED_MIN_N`` on EVERY backend: a kernel sweep reads the data once,
+like an FG pass (its device time is not measured yet), and the jnp path's
 verified arithmetic binning (``kernels.ref.bin_slots``: multiply/floor/clip
 slots checked against the realized edges, factored one-hot reduction)
 brought the CPU sweep from ~25-70x a fused pass down to ~2-4x (below one
@@ -179,11 +179,13 @@ def _resolve_method(method: Optional[str], n: int,
 
 def _resolve_nbins(nbins: Optional[int], backend: Optional[str],
                    dtype=None) -> int:
-    """``None`` -> the backend-tuned sweep width: ``DEF_NBINS`` (128) where
-    the histogram kernels bin in-register (slot count is nearly free),
-    ``DEF_NBINS_JNP`` (16) on the jnp path where the factored reduction's
-    cost is ~linear in the slot count.  Both resolve 1M -> cap in 2 sweeps;
-    explicit values always win.
+    """``None`` -> the backend's sweep width: ``DEF_NBINS`` (128) on the
+    kernel path (not yet timed on a chip: each slot costs the kernel a
+    compare and a row reduction per element tile, so 128 bins may make the
+    sweep compute-bound rather than HBM-bound), ``DEF_NBINS_JNP`` (16) on
+    the jnp path where the factored reduction's cost is ~linear in the slot
+    count.  Both resolve 1M -> cap in 2 sweeps; explicit values always
+    win.
 
     ``dtype``: the data's (promoted) dtype — f64 inputs are rerouted by
     ``kernels.ops`` to the jnp oracle even when the kernel path was

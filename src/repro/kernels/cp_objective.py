@@ -22,13 +22,27 @@ pre-unification ones).
 Counts are carried as int32 (f32 mantissa overflows beyond 2^24 elements —
 the paper's n reaches 1.34e8).
 
-Layout notes (TPU-native, not a CUDA port):
+Layout notes (TPU-native, not a CUDA port; every rule below is one the
+Mosaic compiler enforces):
   * last dim is the 128-lane VPU axis; ``block_rows`` a multiple of 8
     (f32 sublane tiling) — default (512, 128) = 256 KiB f32 per input tile,
-    comfortably inside ~16 MiB VMEM with double buffering;
-  * the pivot ``y`` is an SMEM scalar (prefetched, uniform across the tile);
-  * masking by global element index handles the tail block, so any ``n``
-    is supported without host-side padding corrections;
+    comfortably inside the default scoped VMEM with double buffering;
+    short rows shrink the tile to the row (:func:`_fit_block_rows`);
+  * pivots and slot bounds live in SMEM (uniform across the tile, read as
+    scalars and splat against the tile) — the body cannot load from
+    ``pl.ANY`` memory;
+  * every FG tile reduction keeps its dims ((1, 1) vectors), and each
+    grid step writes ONE lane-padded row per pivot/bracket: partial ``i``
+    in lane ``i`` (FG) or slot ``s`` in lane ``s`` (histogram).  Scalars
+    cannot be stored to VMEM, and a row-per-step output keeps every block
+    at its array's full trailing dims;
+  * the histogram visits slots in a loop, reducing each slot over the
+    tile's rows only into a ``(width, 128)`` VMEM scratch row, and
+    reduces lanes once per tile (transpose + row sum); its counts come out
+    cumulative (one compare per slot) and are differenced outside;
+  * masking by global element index handles the tail block (masked
+    elements become NaN, which no comparison admits), so any ``n`` is
+    supported without host-side padding corrections;
   * scalar (one-pivot) entry points are the K=1 view of the multi-pivot
     kernels — same tile reductions, same block tree-reduce, one less body
     to tune.
@@ -40,15 +54,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 128
 DEF_BLOCK_ROWS = 512
 
-# The histogram kernels build a (block_rows, LANES, nbins + 2) one-hot
-# intermediate per tile (nbins comes from the caller's edge array; the
-# engine default lives in core.selection.DEF_NBINS); 64 rows keeps that
-# under ~4 MiB f32 in VMEM at the default 128 bins.
-DEF_HIST_BLOCK_ROWS = 64
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole array, scalar reads
+
+
+def _round_up(a: int, m: int) -> int:
+    return -(-a // m) * m
+
+
+def _fit_block_rows(n: int, block_rows: int) -> int:
+    """Shrink the tile to the (8-row-rounded) row length when a row is
+    shorter than one tile, so a short row is not padded to a full tile."""
+    return max(8, min(block_rows, _round_up(-(-n // LANES), 8)))
 
 
 def _pad_to_tiles(x: jax.Array, block_rows: int):
@@ -71,11 +92,33 @@ def _pad_to_tiles(x: jax.Array, block_rows: int):
     return x.reshape(x.shape[:-1] + (nblocks * block_rows, LANES)), nblocks
 
 
-def _valid_mask(b, shape, n, block_rows):
-    """Tail mask for tile ``b`` of the grid: global element index < n."""
-    rows = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-    cols = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return (b * block_rows + rows) * LANES + cols < n
+def _load_tile(x_ref, w_ref, b, n, block_rows):
+    """Tile prologue: f32 cast, and the tail (global element index >= n)
+    set to NaN — no ``<``/``<=``/``>`` admits NaN, so masked elements fall
+    out of every count, mass and sum without a per-use mask."""
+    x = x_ref[...].reshape(block_rows, LANES).astype(jnp.float32)
+    rows = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    valid = (b * block_rows + rows) * LANES + cols < n
+    x = jnp.where(valid, x, jnp.float32(jnp.nan))
+    if w_ref is None:
+        return x, None
+    return x, w_ref[...].reshape(block_rows, LANES).astype(jnp.float32)
+
+
+def _tile_sum(v, dtype=jnp.float32):
+    """Whole-tile reduction that keeps its dims: ``(1, 1)``."""
+    return jnp.sum(v, axis=(0, 1), keepdims=True, dtype=dtype)
+
+
+def _lane_row(vals, width, dtype):
+    """Place ``(1, 1)`` partials in lanes ``0..len(vals)-1`` of one
+    ``(1, width)`` row."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+    row = jnp.zeros((1, width), dtype)
+    for i, v in enumerate(vals):
+        row = jnp.where(lane == i, v, row)
+    return row
 
 
 # ---------------------------------------------------------------------------
@@ -83,8 +126,8 @@ def _valid_mask(b, shape, n, block_rows):
 # ---------------------------------------------------------------------------
 
 
-def _fg_tile(x, valid, y, w=None):
-    """Per-tile additive FG partials for one pivot.
+def _fg_tile(x, y, w=None):
+    """Per-tile additive FG partials for one pivot, each ``(1, 1)``.
 
     Counting leg (``w=None``): ``((sum_pos, sum_neg), (n_lt, n_le))``.
     Weights leg: ``((wsum_pos, wsum_neg, w_lt, w_le), (n_lt, n_le))`` — the
@@ -95,54 +138,66 @@ def _fg_tile(x, valid, y, w=None):
     d = x - y
     zero = jnp.zeros_like(x)
     if w is None:
-        fsums = (jnp.sum(jnp.where(valid & (d > 0), d, zero)),
-                 jnp.sum(jnp.where(valid & (d < 0), -d, zero)))
+        fsums = (_tile_sum(jnp.where(d > 0, d, zero)),
+                 _tile_sum(jnp.where(d < 0, -d, zero)))
     else:
-        fsums = (jnp.sum(jnp.where(valid & (d > 0), w * d, zero)),
-                 jnp.sum(jnp.where(valid & (d < 0), -w * d, zero)),
-                 jnp.sum(jnp.where(valid & (d < 0), w, zero)),
-                 jnp.sum(jnp.where(valid & (d <= 0), w, zero)))
+        fsums = (_tile_sum(jnp.where(d > 0, w * d, zero)),
+                 _tile_sum(jnp.where(d < 0, -w * d, zero)),
+                 _tile_sum(jnp.where(d < 0, w, zero)),
+                 _tile_sum(jnp.where(d <= 0, w, zero)))
     # dtype pinned: under global x64 an unpinned int sum accumulates int64,
     # which the int32 output refs reject (and the engine carries int32)
-    cnts = (jnp.sum(valid & (d < 0), dtype=jnp.int32),
-            jnp.sum(valid & (d <= 0), dtype=jnp.int32))
+    cnts = (_tile_sum(d < 0, jnp.int32), _tile_sum(d <= 0, jnp.int32))
     return fsums, cnts
 
 
-def _bin_tile(x, valid, lower, upper, w=None, want_sums=True):
-    """Per-tile slot partials for one bracket's ``(nbins + 2,)`` bounds.
+def _bin_tile(x, bound, nslots, acc_refs, w=None, want_sums=True):
+    """Per-tile slot partials for one bracket, each a ``(1, width)`` row
+    with slot ``s`` in lane ``s`` (``width >= nslots``, lane-padded).
 
-    Counting leg: ``(cnt, bsum)``; weights leg: ``(cnt, wcnt, wsum)`` —
-    per-slot element count, weight mass and ``sum(w*x)``.  The one-hot
-    membership intermediate is ``(block_rows, LANES, nbins + 2)`` — callers
-    bound ``block_rows`` accordingly (DEF_HIST_BLOCK_ROWS).
+    ``bound(s) -> (lower_s, upper_s)`` reads the slot's realized bounds as
+    scalars; ``lower_0`` is NaN, which no ``x <= lower_0`` admits.  The
+    first output is CUMULATIVE: lane ``s`` holds ``count(x <= upper_s)``,
+    one compare per slot; the slot counts are its differences, taken
+    outside the kernel (exact: the bounds are monotone).  The per-slot
+    sums need the slot mask ``x <= upper_s and not x <= lower_s``:
+    counting leg ``bsum``; weights leg ``(wcnt, wsum)`` — weight mass and
+    ``sum(w*x)``.
+
+    Each slot reduces the ``(block_rows, LANES)`` tile over rows only, into
+    row ``s`` of a ``(width, LANES)`` VMEM scratch per output
+    (``acc_refs``); the lane reduction runs once per tile, on the
+    transposed scratch.
 
     ``want_sums=False`` (static) drops the trailing per-slot sum — only
     the in-bin polish reads ``bsum``/``wsum``; plain binned sweeps skip
     that accumulator and its HBM writeback entirely (the weighted mass
     vector ``wcnt`` always rides: it IS the weighted narrowing signal).
     """
-    nslots = lower.shape[-1]
-    j = jax.lax.broadcasted_iota(jnp.int32, (1, 1, nslots), 2)
-    lo3 = lower.reshape(1, 1, nslots)
-    up3 = upper.reshape(1, 1, nslots)
-    x3 = x[:, :, None]
-    # slot 0 has no lower bound — `x > -inf` would drop x == -inf, so the
-    # first slot escapes the strict lower test (keeps sum(cnt) == n and
-    # parity with the searchsorted oracle)
-    m = valid[:, :, None] & ((x3 > lo3) | (j == 0)) & (x3 <= up3)
-    cnt = jnp.sum(m, axis=(0, 1), dtype=jnp.int32)
-    if w is None:
-        if not want_sums:
-            return (cnt,)
-        return (cnt, jnp.sum(jnp.where(m, x3, jnp.float32(0.0)),
-                             axis=(0, 1)))
-    w3 = w[:, :, None]
-    wcnt = jnp.sum(jnp.where(m, w3, jnp.float32(0.0)), axis=(0, 1))
-    if not want_sums:
-        return (cnt, wcnt)
-    wsum = jnp.sum(jnp.where(m, w3 * x3, jnp.float32(0.0)), axis=(0, 1))
-    return (cnt, wcnt, wsum)
+    zero = jnp.zeros_like(x)
+
+    def rows_sum(v, dtype=jnp.float32):
+        return jnp.sum(v, axis=0, keepdims=True, dtype=dtype)
+
+    def slot(s, carry):
+        lo, up = bound(s)
+        le = x <= up
+        vals = [rows_sum(le, jnp.int32)]
+        if w is not None or want_sums:
+            m = le & jnp.logical_not(x <= lo)
+            if w is not None:
+                vals.append(rows_sum(jnp.where(m, w, zero)))
+            if want_sums:
+                vals.append(rows_sum(jnp.where(m, x if w is None else w * x,
+                                               zero)))
+        for ref, v in zip(acc_refs, vals):
+            ref[pl.ds(s, 1), :] = v
+        return carry
+
+    jax.lax.fori_loop(0, nslots, slot, 0)
+    # (width, LANES) -> (LANES, width): slot s lands in lane s
+    return tuple(jnp.sum(ref[...].T, axis=0, keepdims=True)
+                 for ref in acc_refs)
 
 
 # ---------------------------------------------------------------------------
@@ -154,86 +209,82 @@ def _bin_tile(x, valid, lower, upper, w=None, want_sums=True):
 
 def _fg_kernel_multi(y_ref, *refs, n, npiv, block_rows, weighted):
     """One x (or x/w) tile, ALL K pivots: the tile is read HBM -> VMEM once
-    and the K per-pivot partial vectors are computed from registers/VMEM —
+    and the K per-pivot partial rows are computed from registers/VMEM —
     K× less HBM traffic than K independent passes (the win behind shared-x
     batched selection: a quantile set costs one sweep per iteration, not
     K).  K is static (the pivot vector's shape), so the pivot loop is
     unrolled at trace time; all stores use static indices.  Scalar
     ``cp_partials`` / ``wcp_partials`` are the K=1 view."""
-    b = pl.program_id(0)
-    if weighted:
-        x_ref, w_ref, fsum_ref, cnt_ref = refs
-    else:
-        x_ref, fsum_ref, cnt_ref = refs
-    x = x_ref[...].astype(jnp.float32)  # (block_rows, LANES)
-    w = w_ref[...].astype(jnp.float32) if weighted else None
-    valid = _valid_mask(b, x.shape, n, block_rows)
+    x_ref, w_ref = refs[0], (refs[1] if weighted else None)
+    fsum_ref, cnt_ref = refs[-2:]
+    x, w = _load_tile(x_ref, w_ref, pl.program_id(0), n, block_rows)
     for j in range(npiv):  # static unroll: npiv is a trace-time constant
-        fsums, cnts = _fg_tile(x, valid, y_ref[j], w)
-        for i, v in enumerate(fsums):
-            fsum_ref[0, j, i] = v
-        for i, v in enumerate(cnts):
-            cnt_ref[0, j, i] = v
+        fsums, cnts = _fg_tile(x, y_ref[j], w)
+        fsum_ref[0, j:j + 1, :] = _lane_row(fsums, LANES, jnp.float32)
+        cnt_ref[0, j:j + 1, :] = _lane_row(cnts, LANES, jnp.int32)
 
 
 def _fg_kernel_batched(y_ref, *refs, n, block_rows, weighted):
-    """Row-wise body: grid (B, nblocks), one pivot per problem row."""
-    r = pl.program_id(0)  # problem row
-    b = pl.program_id(1)  # block within the row
-    if weighted:
-        x_ref, w_ref, fsum_ref, cnt_ref = refs
-    else:
-        x_ref, fsum_ref, cnt_ref = refs
-    x = x_ref[0].astype(jnp.float32)  # (block_rows, LANES)
-    w = w_ref[0].astype(jnp.float32) if weighted else None
-    valid = _valid_mask(b, x.shape, n, block_rows)
-    fsums, cnts = _fg_tile(x, valid, y_ref[r], w)
-    for i, v in enumerate(fsums):
-        fsum_ref[0, 0, i] = v
-    for i, v in enumerate(cnts):
-        cnt_ref[0, 0, i] = v
+    """Row-wise body: grid (B, nblocks), one pivot per problem row;
+    ``y_ref`` is this row's SMEM ``(1, 1, 1)`` pivot block."""
+    x_ref, w_ref = refs[0], (refs[1] if weighted else None)
+    fsum_ref, cnt_ref = refs[-2:]
+    x, w = _load_tile(x_ref, w_ref, pl.program_id(1), n, block_rows)
+    fsums, cnts = _fg_tile(x, y_ref[0, 0, 0], w)
+    fsum_ref[...] = _lane_row(fsums, LANES, jnp.float32).reshape(
+        fsum_ref.shape)
+    cnt_ref[...] = _lane_row(cnts, LANES, jnp.int32).reshape(cnt_ref.shape)
 
 
-def _hist_kernel_multi(y_ref, *refs, n, npiv, block_rows, weighted,
+def _split_outs(refs):
+    """Histogram bodies get their outputs, then one scratch per output."""
+    half = len(refs) // 2
+    return refs[:half], refs[half:]
+
+
+def _hist_kernel_multi(b_ref, *refs, n, npiv, nslots, block_rows, weighted,
                        want_sums):
     """One x (or x/w) tile, ALL K brackets: like :func:`_fg_kernel_multi`,
     the tile is resident once and every live bracket's histogram is
-    computed from it (K static, bracket loop unrolls at trace time)."""
-    b = pl.program_id(0)
-    if weighted:
-        x_ref, w_ref, *out_refs = refs
-    else:
-        x_ref, *out_refs = refs
-    x = x_ref[...].astype(jnp.float32)  # (block_rows, LANES)
-    w = w_ref[...].astype(jnp.float32) if weighted else None
-    valid = _valid_mask(b, x.shape, n, block_rows)
+    computed from it (K static, bracket loop unrolls at trace time).
+    ``b_ref`` is the SMEM ``(2, K, nslots)`` lower/upper slot bounds;
+    ``refs`` are the data tiles, the outputs and one scratch per output."""
+    x_ref, w_ref = refs[0], (refs[1] if weighted else None)
+    out_refs, acc_refs = _split_outs(refs[2 if weighted else 1:])
+    x, w = _load_tile(x_ref, w_ref, pl.program_id(0), n, block_rows)
     for j in range(npiv):  # static unroll
-        outs = _bin_tile(x, valid, y_ref[0, j], y_ref[1, j], w,
-                         want_sums=want_sums)
+        outs = _bin_tile(x, lambda s: (b_ref[0, j, s], b_ref[1, j, s]),
+                         nslots, acc_refs, w, want_sums=want_sums)
         for ref, v in zip(out_refs, outs):
-            ref[0, j, :] = v
+            ref[0, j:j + 1, :] = v
 
 
-def _hist_kernel_batched(y_ref, *refs, n, block_rows, weighted, want_sums):
-    """Row-wise histogram body: grid (B, nblocks), per-row slot bounds."""
-    r = pl.program_id(0)  # problem row
-    b = pl.program_id(1)  # block within the row
-    if weighted:
-        x_ref, w_ref, *out_refs = refs
-    else:
-        x_ref, *out_refs = refs
-    x = x_ref[0].astype(jnp.float32)  # (block_rows, LANES)
-    w = w_ref[0].astype(jnp.float32) if weighted else None
-    valid = _valid_mask(b, x.shape, n, block_rows)
-    outs = _bin_tile(x, valid, y_ref[0, r], y_ref[1, r], w,
-                     want_sums=want_sums)
+def _hist_kernel_batched(b_ref, *refs, n, nslots, block_rows, weighted,
+                         want_sums):
+    """Row-wise histogram body: grid (B, nblocks), per-row slot bounds.
+    ``b_ref`` is this row's SMEM ``(1, 2, nslots)`` block."""
+    x_ref, w_ref = refs[0], (refs[1] if weighted else None)
+    out_refs, acc_refs = _split_outs(refs[2 if weighted else 1:])
+    x, w = _load_tile(x_ref, w_ref, pl.program_id(1), n, block_rows)
+    outs = _bin_tile(x, lambda s: (b_ref[0, 0, s], b_ref[0, 1, s]),
+                     nslots, acc_refs, w, want_sums=want_sums)
     for ref, v in zip(out_refs, outs):
-        ref[0, 0, :] = v
+        ref[...] = v.reshape(ref.shape)
 
 
 # ---------------------------------------------------------------------------
 # pallas_call builders (shared pad/spec/tree-reduce plumbing)
 # ---------------------------------------------------------------------------
+
+
+def _tiles(x, w, block_rows):
+    """Pad ``x`` (and ``w``) to tiles; ``(data, nblocks, block_rows)``."""
+    block_rows = _fit_block_rows(x.shape[-1], block_rows)
+    x2, nblocks = _pad_to_tiles(x, block_rows)
+    data = [x2]
+    if w is not None:
+        data.append(_pad_to_tiles(w, block_rows)[0])
+    return data, nblocks, block_rows
 
 
 def _fg_call_multi(x, w, y, *, block_rows, interpret):
@@ -242,31 +293,28 @@ def _fg_call_multi(x, w, y, *, block_rows, interpret):
     weighted = w is not None
     n = x.size
     npiv = y.shape[0]
-    x2, nblocks = _pad_to_tiles(x.reshape(-1), block_rows)
-    data = [x2]
-    if weighted:
-        data.append(_pad_to_tiles(w.reshape(-1), block_rows)[0])
+    data, nblocks, block_rows = _tiles(
+        x.reshape(-1), None if w is None else w.reshape(-1), block_rows)
     y = jnp.asarray(y, jnp.float32).reshape(npiv)
     nf = 4 if weighted else 2
 
+    out = pl.BlockSpec((1, npiv, LANES), lambda i: (i, 0, 0))
     fsum, cnt = pl.pallas_call(
         functools.partial(_fg_kernel_multi, n=n, npiv=npiv,
                           block_rows=block_rows, weighted=weighted),
         grid=(nblocks,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)]  # y: tiny, whole-array
+        in_specs=[_SMEM]
         + [pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))] * len(data),
-        out_specs=[
-            pl.BlockSpec((1, npiv, nf), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, npiv, 2), lambda i: (i, 0, 0)),
-        ],
+        out_specs=[out, out],
         out_shape=[
-            jax.ShapeDtypeStruct((nblocks, npiv, nf), jnp.float32),
-            jax.ShapeDtypeStruct((nblocks, npiv, 2), jnp.int32),
+            jax.ShapeDtypeStruct((nblocks, npiv, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((nblocks, npiv, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(y, *data)
-    s = jnp.sum(fsum, axis=0)
-    c = jnp.sum(cnt, axis=0, dtype=jnp.int32)  # int32 under global x64 too
+    s = jnp.sum(fsum[..., :nf], axis=0)
+    # int32 under global x64 too
+    c = jnp.sum(cnt[..., :2], axis=0, dtype=jnp.int32)
     return tuple(s[:, i] for i in range(nf)) + (c[:, 0], c[:, 1])
 
 
@@ -274,50 +322,60 @@ def _fg_call_batched(x, w, y, *, block_rows, interpret):
     """Row-wise launch over (B, n) problems; returns (B,) partial vectors."""
     weighted = w is not None
     bsz, n = x.shape
-    x3, nblocks = _pad_to_tiles(x, block_rows)
-    data = [x3]
-    if weighted:
-        data.append(_pad_to_tiles(w, block_rows)[0])
-    y = jnp.asarray(y, jnp.float32).reshape(bsz)
+    data, nblocks, block_rows = _tiles(x, w, block_rows)
+    y = jnp.asarray(y, jnp.float32).reshape(bsz, 1, 1)
     nf = 4 if weighted else 2
 
+    # one (1, LANES) row per grid step: trailing block dims == array dims
+    out = pl.BlockSpec((1, 1, 1, LANES), lambda r, b: (r, b, 0, 0))
     fsum, cnt = pl.pallas_call(
         functools.partial(_fg_kernel_batched, n=n, block_rows=block_rows,
                           weighted=weighted),
         grid=(bsz, nblocks),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
+        # this row's pivot only: SMEM holds one scalar, not (B,)
+        in_specs=[pl.BlockSpec((1, 1, 1), lambda r, b: (r, 0, 0),
+                               memory_space=pltpu.SMEM)]
         + [pl.BlockSpec((1, block_rows, LANES),
                         lambda r, b: (r, b, 0))] * len(data),
-        out_specs=[
-            pl.BlockSpec((1, 1, nf), lambda r, b: (r, b, 0)),
-            pl.BlockSpec((1, 1, 2), lambda r, b: (r, b, 0)),
-        ],
+        out_specs=[out, out],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, nblocks, nf), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, nblocks, 2), jnp.int32),
+            jax.ShapeDtypeStruct((bsz, nblocks, 1, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, nblocks, 1, LANES), jnp.int32),
         ],
         interpret=interpret,
     )(y, *data)
-    s = jnp.sum(fsum, axis=1)
-    c = jnp.sum(cnt, axis=1, dtype=jnp.int32)  # int32 under global x64 too
+    s = jnp.sum(fsum[:, :, 0, :nf], axis=1)
+    # int32 under global x64 too
+    c = jnp.sum(cnt[:, :, 0, :2], axis=1, dtype=jnp.int32)
     return tuple(s[..., i] for i in range(nf)) + (c[..., 0], c[..., 1])
 
 
 def _slot_bounds(edges):
     """``(..., nbins+1)`` edges -> ``(..., nbins+2)`` (lower, upper) slot
     bounds.  Pure concatenation — NO fp arithmetic (see the exactness
-    contract below)."""
-    ninf = jnp.full_like(edges[..., :1], -jnp.inf)
+    contract below).  Slot 0's lower bound is NaN: ``x <= NaN`` admits
+    nothing, so slot 0 keeps every ``x <= e_0``, ``-inf`` included."""
+    nan = jnp.full_like(edges[..., :1], jnp.nan)
     pinf = jnp.full_like(edges[..., :1], jnp.inf)
-    return (jnp.concatenate([ninf, edges], axis=-1),
+    return (jnp.concatenate([nan, edges], axis=-1),
             jnp.concatenate([edges, pinf], axis=-1))
 
 
-def _hist_out(nout, lead, nslots):
-    """Histogram out_shape list: cnt is int32, the mass/sum slots f32."""
-    return [jax.ShapeDtypeStruct(lead + (nslots,),
-                                 jnp.int32 if i == 0 else jnp.float32)
-            for i in range(nout)]
+def _hist_io(nout, shape, width):
+    """Histogram ``(out_shape, scratch_shapes)``: cnt is int32, the
+    mass/sum slots f32; each output gets a ``(width, LANES)`` scratch."""
+    dts = [jnp.int32] + [jnp.float32] * (nout - 1)
+    return ([jax.ShapeDtypeStruct(shape, dt) for dt in dts],
+            [pltpu.VMEM((width, LANES), dt) for dt in dts])
+
+
+def _slot_counts(outs, want_sums):
+    """Cumulative counts -> slot counts (exact integer differences); the
+    caller gets ``None`` for a dropped sum."""
+    cum = outs[0]
+    cnt = cum - jnp.pad(cum[..., :-1], [(0, 0)] * (cum.ndim - 1) + [(1, 0)])
+    outs = (cnt,) + tuple(outs[1:])
+    return outs if want_sums else outs + (None,)
 
 
 def _hist_call_multi(x, w, edges, *, block_rows, interpret,
@@ -327,29 +385,30 @@ def _hist_call_multi(x, w, edges, *, block_rows, interpret,
     accumulator/HBM writeback) — the caller gets ``None`` in its place."""
     weighted = w is not None
     n = x.size
-    npiv, nbins = edges.shape[0], edges.shape[-1] - 1
-    x2, nblocks = _pad_to_tiles(x.reshape(-1), block_rows)
-    data = [x2]
-    if weighted:
-        data.append(_pad_to_tiles(w.reshape(-1), block_rows)[0])
+    npiv, nslots = edges.shape[0], edges.shape[-1] + 1
+    width = _round_up(nslots, LANES)
+    data, nblocks, block_rows = _tiles(
+        x.reshape(-1), None if w is None else w.reshape(-1), block_rows)
     lower, upper = _slot_bounds(jnp.asarray(edges, jnp.float32))
-    y = jnp.stack([lower, upper])  # (2, K, nbins + 2)
+    bounds = jnp.stack([lower, upper])  # (2, K, nslots)
     nout = (3 if weighted else 2) - (not want_sums)
 
+    out_shape, scratch = _hist_io(nout, (nblocks, npiv, width), width)
     outs = pl.pallas_call(
-        functools.partial(_hist_kernel_multi, n=n, npiv=npiv,
+        functools.partial(_hist_kernel_multi, n=n, npiv=npiv, nslots=nslots,
                           block_rows=block_rows, weighted=weighted,
                           want_sums=want_sums),
         grid=(nblocks,),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)]  # slot bounds: tiny
+        in_specs=[_SMEM]
         + [pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))] * len(data),
-        out_specs=[pl.BlockSpec((1, npiv, nbins + 2),
+        out_specs=[pl.BlockSpec((1, npiv, width),
                                 lambda i: (i, 0, 0))] * nout,
-        out_shape=_hist_out(nout, (nblocks, npiv), nbins + 2),
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(y, *data)
-    outs = tuple(jnp.sum(o, axis=0, dtype=o.dtype) for o in outs)
-    return outs if want_sums else outs + (None,)
+    )(bounds, *data)
+    return _slot_counts(tuple(jnp.sum(o[..., :nslots], axis=0, dtype=o.dtype)
+                              for o in outs), want_sums)
 
 
 def _hist_call_batched(x, w, edges, *, block_rows, interpret,
@@ -357,30 +416,35 @@ def _hist_call_batched(x, w, edges, *, block_rows, interpret,
     """Row-wise histogram launch: per-row slot vectors ``(B, nbins + 2)``."""
     weighted = w is not None
     bsz, n = x.shape
-    nbins = edges.shape[-1] - 1
-    x3, nblocks = _pad_to_tiles(x, block_rows)
-    data = [x3]
-    if weighted:
-        data.append(_pad_to_tiles(w, block_rows)[0])
+    nslots = edges.shape[-1] + 1
+    width = _round_up(nslots, LANES)
+    data, nblocks, block_rows = _tiles(x, w, block_rows)
     lower, upper = _slot_bounds(
-        jnp.asarray(edges, jnp.float32).reshape(bsz, nbins + 1))
-    y = jnp.stack([lower, upper])  # (2, B, nbins + 2)
+        jnp.asarray(edges, jnp.float32).reshape(bsz, nslots - 1))
+    bounds = jnp.stack([lower, upper], axis=1)  # (B, 2, nslots)
     nout = (3 if weighted else 2) - (not want_sums)
 
+    out_shape, scratch = _hist_io(nout, (bsz, nblocks, 1, width), width)
     outs = pl.pallas_call(
-        functools.partial(_hist_kernel_batched, n=n, block_rows=block_rows,
-                          weighted=weighted, want_sums=want_sums),
+        functools.partial(_hist_kernel_batched, n=n, nslots=nslots,
+                          block_rows=block_rows, weighted=weighted,
+                          want_sums=want_sums),
         grid=(bsz, nblocks),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
+        # this row's bounds only: SMEM holds (2, nslots), not (B, 2, nslots)
+        in_specs=[pl.BlockSpec((1, 2, nslots), lambda r, b: (r, 0, 0),
+                               memory_space=pltpu.SMEM)]
         + [pl.BlockSpec((1, block_rows, LANES),
                         lambda r, b: (r, b, 0))] * len(data),
-        out_specs=[pl.BlockSpec((1, 1, nbins + 2),
-                                lambda r, b: (r, b, 0))] * nout,
-        out_shape=_hist_out(nout, (bsz, nblocks), nbins + 2),
+        # one (1, width) row per grid step: trailing block dims == array dims
+        out_specs=[pl.BlockSpec((1, 1, 1, width),
+                                lambda r, b: (r, b, 0, 0))] * nout,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         interpret=interpret,
-    )(y, *data)
-    outs = tuple(jnp.sum(o, axis=1, dtype=o.dtype) for o in outs)
-    return outs if want_sums else outs + (None,)
+    )(bounds, *data)
+    return _slot_counts(tuple(jnp.sum(o[:, :, 0, :nslots], axis=1,
+                                      dtype=o.dtype) for o in outs),
+                        want_sums)
 
 
 # ---------------------------------------------------------------------------
@@ -535,7 +599,7 @@ def cp_histogram(
     x: jax.Array,
     edges: jax.Array,
     *,
-    block_rows: int = DEF_HIST_BLOCK_ROWS,
+    block_rows: int = DEF_BLOCK_ROWS,
     interpret: bool = False,
     want_sums: bool = True,
 ):
@@ -562,7 +626,7 @@ def cp_histogram_batched(
     x: jax.Array,
     edges: jax.Array,
     *,
-    block_rows: int = DEF_HIST_BLOCK_ROWS,
+    block_rows: int = DEF_BLOCK_ROWS,
     interpret: bool = False,
     want_sums: bool = True,
 ):
@@ -579,7 +643,7 @@ def cp_histogram_multi(
     x: jax.Array,
     edges: jax.Array,
     *,
-    block_rows: int = DEF_HIST_BLOCK_ROWS,
+    block_rows: int = DEF_BLOCK_ROWS,
     interpret: bool = False,
     want_sums: bool = True,
 ):
@@ -597,7 +661,7 @@ def wcp_histogram(
     w: jax.Array,
     edges: jax.Array,
     *,
-    block_rows: int = DEF_HIST_BLOCK_ROWS,
+    block_rows: int = DEF_BLOCK_ROWS,
     interpret: bool = False,
     want_sums: bool = True,
 ):
@@ -623,7 +687,7 @@ def wcp_histogram_batched(
     w: jax.Array,
     edges: jax.Array,
     *,
-    block_rows: int = DEF_HIST_BLOCK_ROWS,
+    block_rows: int = DEF_BLOCK_ROWS,
     interpret: bool = False,
     want_sums: bool = True,
 ):
@@ -641,7 +705,7 @@ def wcp_histogram_multi(
     w: jax.Array,
     edges: jax.Array,
     *,
-    block_rows: int = DEF_HIST_BLOCK_ROWS,
+    block_rows: int = DEF_BLOCK_ROWS,
     interpret: bool = False,
     want_sums: bool = True,
 ):

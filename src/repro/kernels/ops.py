@@ -1,7 +1,7 @@
 """jit'd dispatch wrappers around the Pallas kernels.
 
-On TPU the Pallas path is used; on CPU (this container) the pure-jnp oracle
-is numerically identical and XLA fuses it into one pass, so it is the
+On TPU the Pallas path is used; elsewhere the pure-jnp oracle is
+numerically identical and XLA fuses it into one pass, so it is the
 default.  ``backend='pallas_interpret'`` forces the kernel body through the
 Pallas interpreter (Python emulation) — used by the tests to validate the
 TPU kernel logic on CPU.
@@ -24,10 +24,7 @@ from repro.kernels import cp_objective, ref
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def _resolve_backend(backend: str | None, x: jax.Array) -> str:
@@ -47,8 +44,8 @@ def _resolve_impl(impl: str | None) -> str:
     slotting (bit-identical to the searchsorted oracle by construction, see
     ``ref.bin_slots``) whose factored one-hot reduction is what makes the
     CPU histogram pass competitive with a fused FG pass.  ``'searchsorted'``
-    stays selectable for differential testing.  The Pallas kernels bin
-    in-register against the resident edges (neither slotting applies), so
+    stays selectable for differential testing.  The Pallas kernels compare
+    each tile against the resident edges (neither slotting applies), so
     ``impl`` only routes the jnp-oracle path — including the f64 reroute.
     """
     if impl is None:

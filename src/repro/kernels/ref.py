@@ -54,6 +54,10 @@ BIN_IMPLS = ("searchsorted", "arithmetic")
 # what makes the arithmetic pass map-reduce-fast on CPU.
 HIST_CHUNK = 1 << 14
 
+# The one-hot contractions carry data values (per-slot sums), so they run
+# at full precision: a TPU's default rounds f32 operands to bf16.
+_EXACT = jax.lax.Precision.HIGHEST
+
 
 def bin_edges(lo, hi, nbins: int):
     """Realized fp bin-edge values ``e_j = clip(lo + w*j, lo, hi)`` with
@@ -292,7 +296,8 @@ def _factored_hist(slot, rows, nslots: int, dt):
         hi_oh = (si[..., None] // bf == ia).astype(dt)   # (r, m, A)
         lo_oh = (si[..., None] % bf == ib).astype(dt)    # (r, m, B)
         contract = lambda lhs: jnp.einsum(
-            "rma,rmb->rab", lhs, lo_oh).reshape(r, -1)[:, :nslots]
+            "rma,rmb->rab", lhs, lo_oh,
+            precision=_EXACT).reshape(r, -1)[:, :nslots]
         cnt = contract(hi_oh)
         out = [acc[0] + cnt.astype(jnp.int32)]
         for k, v in enumerate(args[1:]):
@@ -355,7 +360,8 @@ def _hist_multi_shared(x, edges, rows, nslots: int, dt):
             hi_oh = (si[..., None] // bf == ia).astype(dt)  # (K, m, A)
             lo_oh = (si[..., None] % bf == ib).astype(dt)   # (K, m, B)
             contract = lambda lhs: jnp.einsum(
-                "kma,kmb->kab", lhs, lo_oh).reshape(kk, -1)[:, :nslots]
+                "kma,kmb->kab", lhs, lo_oh,
+                precision=_EXACT).reshape(kk, -1)[:, :nslots]
             out = [acc[0] + contract(hi_oh).astype(jnp.int32)]
             for i, v in enumerate(args[2:]):
                 out.append(acc[i + 1] + contract(hi_oh * v[None, :, None]))

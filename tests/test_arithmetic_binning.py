@@ -282,7 +282,7 @@ def test_x64_reroute_keeps_arithmetic_exact(use_x64):
     import jax.experimental
 
     if use_x64:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             base = np.float64(1.0)
             eps = np.finfo(np.float64).eps
             x = jnp.asarray(base + np.arange(64) * 50 * eps)

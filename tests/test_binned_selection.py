@@ -303,7 +303,7 @@ def test_x64_parity_sub_f32_resolution():
 
     from repro.kernels import ops
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         base = 1.0
         eps = 1e-12  # far below f32 ulp at 1.0 (~1.2e-7)
         vals = np.array([base + i * eps for i in range(-40, 41)], np.float64)

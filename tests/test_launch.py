@@ -3,8 +3,6 @@ import os
 import subprocess
 import sys
 
-import pytest
-
 import jax
 
 from repro.configs import SHAPES, get_config
@@ -21,12 +19,6 @@ def test_plan_rules_no_mesh():
     assert plan.mesh is None and plan.tp == 1
 
 
-@pytest.mark.skipif(
-    tuple(int(p) for p in jax.__version__.split(".")[:2]) < (0, 5),
-    reason="jax 0.4.x shard_map mistransposes the fused vocab loss in the "
-           "dry-run path (observed on the container's jax 0.4.37; passes "
-           "on jax >= 0.5) — see ROADMAP open items; re-enable on bump",
-)
 def test_mini_dryrun_subprocess():
     """Full launch path (lower+compile+analyze) on an 8-device host mesh."""
     env = dict(os.environ)

@@ -368,7 +368,7 @@ def test_weighted_dtype_sweep(ints, use_f64, wf):
     w32 = rng.integers(1, 5, n).astype(np.float32)
     wk = float(np.float32(max(float(w32.sum()) * wf / 1000.0, 0.5)))
     if use_f64:
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             x = x32.astype(np.float64)
             w = w32.astype(np.float64)
             res = selection.weighted_order_statistic(

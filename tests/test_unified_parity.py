@@ -125,7 +125,7 @@ def test_rows_and_shared_modes_parity(method):
 def test_x64_sub_f32_resolution(method):
     """f64 data whose gaps vanish at f32 resolution: the ops-layer reroute
     must keep the unified binned loops exact under x64."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         base = np.float64(1.0)
         x = base + np.arange(2000, dtype=np.float64) * 1e-12
         rng = np.random.default_rng(43)

@@ -202,7 +202,7 @@ def test_weighted_x64_sub_f32_resolution():
     dispatch reroutes to the dtype-preserving oracles and stays exact."""
     import jax.experimental
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         base, eps = 1.0, 1e-12
         vals = np.array([base + i * eps for i in range(-30, 31)], np.float64)
         rng = np.random.default_rng(13)
