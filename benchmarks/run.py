@@ -6,7 +6,6 @@
   outlier_bench        Sec. V-D / Fig. 5 (extreme values)
   hybrid_breakdown     Sec. IV (CP iterations vs pivot-interval handoff)
   regression_bench     Sec. VI (LMS/LTS/kNN)
-  roofline_bench       EXPERIMENTS.md §Roofline source (from dry-run cache)
 
 Prints ``name,us_per_call,derived`` CSV.  ``--full`` uses paper-scale sizes.
 ``--json`` additionally writes the selection perf trajectory (grid point,
@@ -49,7 +48,6 @@ def main() -> None:
         hybrid_breakdown_bench,
         outlier_bench,
         regression_bench,
-        roofline_bench,
         selection_bench,
     )
 
@@ -61,7 +59,6 @@ def main() -> None:
         "hybrid": hybrid_breakdown_bench,
         "regression": regression_bench,
         "clip": clip_bench,
-        "roofline": roofline_bench,
     }
     failed = []
     for name, mod in benches.items():

@@ -191,51 +191,53 @@ def local_order_statistic(
     weighted = weights is not None
     if weighted:
         weights = jnp.asarray(weights).reshape(-1)
-    # the evaluator owns the data layout AND the measure: local fused pass
-    # (Pallas on TPU) + psum of the additive partials is the whole
-    # multi-device story
-    ev = ShardedEvaluator(x_local, k, axes, backend=backend, weights=weights,
-                          binned_impl=binned_impl)
-    kk = ev.k
-    dtype = x_local.dtype
-    wl = weights.astype(kk.dtype) if weighted else None
+    with jax.named_scope("sel.seed"):
+        # the evaluator owns the data layout AND the measure: local fused
+        # pass (Pallas on TPU) + psum of the additive partials is the whole
+        # multi-device story
+        ev = ShardedEvaluator(x_local, k, axes, backend=backend,
+                              weights=weights, binned_impl=binned_impl)
+        kk = ev.k
+        dtype = x_local.dtype
+        wl = weights.astype(kk.dtype) if weighted else None
 
-    xmin, xmax, xmean = ev.init_stats()
+        xmin, xmax, xmean = ev.init_stats()
 
-    # analytic cut seeds, mirroring selection._seed_state's two measure legs
-    if weighted:
-        Wsafe = jnp.maximum(ev.W, jnp.asarray(1e-30, ev.W.dtype))
-        alpha = ((ev.W - kk) / Wsafe).astype(dtype)
-        beta = (kk / Wsafe).astype(dtype)
-        gL0, gR0 = -beta, alpha
-    else:
-        nf = ev.n.astype(dtype)
-        alpha, beta = os_weights(nf, kk, dtype)
-        gL0 = alpha * (1.0 / nf) - beta * (nf - 1.0) / nf
-        gR0 = alpha * (nf - 1.0) / nf - beta * (1.0 / nf)
+        # analytic cut seeds, mirroring selection._seed_state's two measure
+        # legs
+        if weighted:
+            Wsafe = jnp.maximum(ev.W, jnp.asarray(1e-30, ev.W.dtype))
+            alpha = ((ev.W - kk) / Wsafe).astype(dtype)
+            beta = (kk / Wsafe).astype(dtype)
+            gL0, gR0 = -beta, alpha
+        else:
+            nf = ev.n.astype(dtype)
+            alpha, beta = os_weights(nf, kk, dtype)
+            gL0 = alpha * (1.0 / nf) - beta * (nf - 1.0) / nf
+            gR0 = alpha * (nf - 1.0) / nf - beta * (1.0 / nf)
 
-    fL0 = beta * (xmean - xmin)
-    fR0 = alpha * (xmax - xmean)
-    # analytic Kelley intersection seeds the polish's first in-bin cut
-    # (mirrors selection.binned_loop_batched's polish seeding)
-    t0 = (fR0 - fL0 + xmin * gL0 - xmax * gR0) / (gL0 - gR0)
-    bad0 = ~jnp.isfinite(t0) | (t0 <= xmin) | (t0 >= xmax)
-    t0 = jnp.where(bad0, 0.5 * (xmin + xmax), t0).astype(dtype)
-    s0 = _DistState(
-        yL=xmin,
-        fL=fL0,
-        gL=gL0,
-        yR=xmax,
-        fR=fR0,
-        gR=gR0,
-        loc_cleL=_pcast_varying(jnp.asarray(0, jnp.int32), axes_t),
-        loc_cleR=_pcast_varying(jnp.asarray(n_local, jnp.int32), axes_t),
-        max_in=jnp.asarray(n_local, jnp.int32),
-        t_exact=jnp.asarray(jnp.nan, dtype),
-        found_exact=jnp.asarray(False),
-        it=jnp.asarray(0, jnp.int32),
-        tp=t0,
-    )
+        fL0 = beta * (xmean - xmin)
+        fR0 = alpha * (xmax - xmean)
+        # analytic Kelley intersection seeds the polish's first in-bin cut
+        # (mirrors selection.binned_loop_batched's polish seeding)
+        t0 = (fR0 - fL0 + xmin * gL0 - xmax * gR0) / (gL0 - gR0)
+        bad0 = ~jnp.isfinite(t0) | (t0 <= xmin) | (t0 >= xmax)
+        t0 = jnp.where(bad0, 0.5 * (xmin + xmax), t0).astype(dtype)
+        s0 = _DistState(
+            yL=xmin,
+            fL=fL0,
+            gL=gL0,
+            yR=xmax,
+            fR=fR0,
+            gR=gR0,
+            loc_cleL=_pcast_varying(jnp.asarray(0, jnp.int32), axes_t),
+            loc_cleR=_pcast_varying(jnp.asarray(n_local, jnp.int32), axes_t),
+            max_in=jnp.asarray(n_local, jnp.int32),
+            t_exact=jnp.asarray(jnp.nan, dtype),
+            found_exact=jnp.asarray(False),
+            it=jnp.asarray(0, jnp.int32),
+            tp=t0,
+        )
 
     def cond(carry):
         s, stalled = carry
@@ -372,75 +374,92 @@ def local_order_statistic(
         raise ValueError(f"unknown method {method!r}; one of "
                          f"{DIST_METHODS}")
 
-    s, _ = jax.lax.while_loop(cond, body, (s0, jnp.asarray(False)))
+    with jax.named_scope("sel.sweep"):
+        s, _ = jax.lax.while_loop(cond, body, (s0, jnp.asarray(False)))
 
     # ---- distributed hybrid finalize (compact per shard, gather, sort) ----
     # per-shard compaction by selection.rank_compact (the one rank-gather
     # implementation), then the tiny buffers ride an all_gather
     big = jnp.asarray(jnp.inf, dtype)
-    mask_in = (x_local > s.yL) & (x_local <= s.yR)
+    with jax.named_scope("sel.compact"):
+        mask_in = (x_local > s.yL) & (x_local <= s.yR)
     cols = [(x_local, big)]
     if weighted:
         cols.append((wl, jnp.zeros((), wl.dtype)))
     bufs, loc_in = selection.rank_compact(mask_in, cap_local, cols)
-    n_in = _psum(loc_in, axes)
-    z_all = bufs[0]
-    for ax in axes_t:
-        z_all = jax.lax.all_gather(z_all, ax).reshape(-1)
-    ok_gather = _pmax(loc_in, axes) <= cap_local
-    vnext = _pmin(jnp.min(jnp.where(x_local > s.yL, x_local, big)), axes)
+    with jax.named_scope("sel.compact"):
+        n_in = _psum(loc_in, axes)
+        z_all = bufs[0]
+        for ax in axes_t:
+            z_all = jax.lax.all_gather(z_all, ax).reshape(-1)
+        ok_gather = _pmax(loc_in, axes) <= cap_local
+    with jax.named_scope("sel.probe"):
+        vnext = _pmin(jnp.min(jnp.where(x_local > s.yL, x_local, big)),
+                      axes)
 
     if weighted:
         # gather the aligned weight buffers and resolve by sorted prefix
         # masses — the weighted generalization of indexing at k - cL
-        zw_all = bufs[1]
-        for ax in axes_t:
-            zw_all = jax.lax.all_gather(zw_all, ax).reshape(-1)
-        order = jnp.argsort(z_all)
-        zs = z_all[order]
-        cLm = _psum(jnp.sum(jnp.where(x_local <= s.yL, wl, 0),
-                            dtype=wl.dtype), axes)
-        cumw = cLm + jnp.cumsum(zw_all[order])
-        reach = cumw >= kk
-        ans_sort = zs[jnp.argmax(reach).astype(jnp.int32)]
-        # the buffer certifies only when its total mass actually reaches wk
-        ok_sort = ok_gather & reach[-1]
-        m_le_v = _psum(jnp.sum(jnp.where(x_local <= vnext, wl, 0),
-                               dtype=wl.dtype), axes)
-        m_lt_max = _psum(jnp.sum(jnp.where(x_local < xmax, wl, 0),
-                                 dtype=wl.dtype), axes)
-        # extreme shortcuts gated on the seed bracket (see the engine
-        # finalize: re-measured masses can rounding-flip near wk; only a
-        # bracket still AT the extreme may certify through them)
-        at_min = (cLm >= kk) & (s.yL == xmin)
-        at_max = (m_lt_max < kk) & (s.yR == xmax)
+        with jax.named_scope("sel.compact"):
+            zw_all = bufs[1]
+            for ax in axes_t:
+                zw_all = jax.lax.all_gather(zw_all, ax).reshape(-1)
+        with jax.named_scope("sel.sort"):
+            order = jnp.argsort(z_all)
+            zs = z_all[order]
+        with jax.named_scope("sel.probe"):
+            cLm = _psum(jnp.sum(jnp.where(x_local <= s.yL, wl, 0),
+                                dtype=wl.dtype), axes)
+        with jax.named_scope("sel.sort"):
+            cumw = cLm + jnp.cumsum(zw_all[order])
+            reach = cumw >= kk
+            ans_sort = zs[jnp.argmax(reach).astype(jnp.int32)]
+            # the buffer certifies only when its total mass actually
+            # reaches wk
+            ok_sort = ok_gather & reach[-1]
+        with jax.named_scope("sel.probe"):
+            m_le_v = _psum(jnp.sum(jnp.where(x_local <= vnext, wl, 0),
+                                   dtype=wl.dtype), axes)
+            m_lt_max = _psum(jnp.sum(jnp.where(x_local < xmax, wl, 0),
+                                     dtype=wl.dtype), axes)
+            # extreme shortcuts gated on the seed bracket (see the engine
+            # finalize: re-measured masses can rounding-flip near wk; only
+            # a bracket still AT the extreme may certify through them)
+            at_min = (cLm >= kk) & (s.yL == xmin)
+            at_max = (m_lt_max < kk) & (s.yR == xmax)
         t_hit = s.t_exact.astype(dtype)
         y_hi = s.yR.astype(dtype)
     else:
-        zs = jax.lax.sort(z_all)
-        cLm = _psum(jnp.sum(x_local <= s.yL, dtype=jnp.int32), axes)
-        ans_sort = zs[jnp.clip(kk - cLm - 1, 0, z_all.size - 1)]
+        with jax.named_scope("sel.sort"):
+            zs = jax.lax.sort(z_all)
+        with jax.named_scope("sel.probe"):
+            cLm = _psum(jnp.sum(x_local <= s.yL, dtype=jnp.int32), axes)
+        with jax.named_scope("sel.sort"):
+            ans_sort = zs[jnp.clip(kk - cLm - 1, 0, z_all.size - 1)]
         ok_sort = ok_gather
-        m_le_v = _psum(jnp.sum(x_local <= vnext, dtype=jnp.int32), axes)
-        m_lt_max = _psum(jnp.sum(x_local < xmax, dtype=jnp.int32), axes)
-        at_min = cLm >= kk
-        at_max = m_lt_max < kk
+        with jax.named_scope("sel.probe"):
+            m_le_v = _psum(jnp.sum(x_local <= vnext, dtype=jnp.int32), axes)
+            m_lt_max = _psum(jnp.sum(x_local < xmax, dtype=jnp.int32), axes)
+            at_min = cLm >= kk
+            at_max = m_lt_max < kk
         t_hit = s.t_exact
         y_hi = s.yR
 
-    fallback_ok = (cLm < kk) & (kk <= m_le_v)
-    value = jnp.where(
-        s.found_exact, t_hit,
-        jnp.where(ok_sort, ans_sort, jnp.where(fallback_ok, vnext, y_hi)),
-    )
-    status = jnp.where(
-        s.found_exact, selection.EXACT_HIT,
-        jnp.where(ok_sort, selection.HYBRID_SORT,
-                  jnp.where(fallback_ok, selection.TIE_FALLBACK,
-                            selection.NOT_CONVERGED)),
-    )
-    value = jnp.where(at_min, xmin, jnp.where(at_max, xmax, value))
-    status = jnp.where(at_min | at_max, selection.EXACT_HIT, status)
+    with jax.named_scope("sel.sort"):
+        fallback_ok = (cLm < kk) & (kk <= m_le_v)
+        value = jnp.where(
+            s.found_exact, t_hit,
+            jnp.where(ok_sort, ans_sort,
+                      jnp.where(fallback_ok, vnext, y_hi)),
+        )
+        status = jnp.where(
+            s.found_exact, selection.EXACT_HIT,
+            jnp.where(ok_sort, selection.HYBRID_SORT,
+                      jnp.where(fallback_ok, selection.TIE_FALLBACK,
+                                selection.NOT_CONVERGED)),
+        )
+        value = jnp.where(at_min, xmin, jnp.where(at_max, xmax, value))
+        status = jnp.where(at_min | at_max, selection.EXACT_HIT, status)
     return selection.SelectResult(
         value=value, iters=s.it, status=status.astype(jnp.int32),
         y_lo=s.yL, y_hi=s.yR, n_in=n_in,
@@ -699,42 +718,50 @@ def multi_order_statistic_across_shards(
 
     def one(args):
         lo, hi = args
-        mask_in = (x_local > lo) & (x_local <= hi)
+        with jax.named_scope("sel.compact"):
+            mask_in = (x_local > lo) & (x_local <= hi)
         bufs, loc_in = selection.rank_compact(mask_in, cap_local, cols)
-        gathered = []
-        for b in bufs:
-            for ax in axes_t:
-                b = jax.lax.all_gather(b, ax)
-            gathered.append(b.reshape(-1))
-        ok = _pmax(loc_in, axes_t) <= cap_local
-        n_in = _psum(loc_in, axes_t)
-        vnext = _pmin(jnp.min(jnp.where(x_local > lo, x_local, bigloc)),
-                      axes_t)
-        if weighted:
-            cLm = _psum(jnp.sum(jnp.where(x_local <= lo, wl, 0),
-                                dtype=mdt), axes_t)
-            m_le_v = _psum(jnp.sum(jnp.where(x_local <= vnext, wl, 0),
-                                   dtype=mdt), axes_t)
-        else:
-            cLm = _psum(jnp.sum(x_local <= lo, dtype=jnp.int32), axes_t)
-            m_le_v = _psum(jnp.sum(x_local <= vnext, dtype=jnp.int32),
-                           axes_t)
+        with jax.named_scope("sel.compact"):
+            gathered = []
+            for b in bufs:
+                for ax in axes_t:
+                    b = jax.lax.all_gather(b, ax)
+                gathered.append(b.reshape(-1))
+            ok = _pmax(loc_in, axes_t) <= cap_local
+            n_in = _psum(loc_in, axes_t)
+        with jax.named_scope("sel.probe"):
+            vnext = _pmin(jnp.min(jnp.where(x_local > lo, x_local, bigloc)),
+                          axes_t)
+            if weighted:
+                cLm = _psum(jnp.sum(jnp.where(x_local <= lo, wl, 0),
+                                    dtype=mdt), axes_t)
+                m_le_v = _psum(jnp.sum(jnp.where(x_local <= vnext, wl, 0),
+                                       dtype=mdt), axes_t)
+            else:
+                cLm = _psum(jnp.sum(x_local <= lo, dtype=jnp.int32), axes_t)
+                m_le_v = _psum(jnp.sum(x_local <= vnext, dtype=jnp.int32),
+                               axes_t)
         return (*gathered, cLm, n_in, ok, vnext, m_le_v)
 
     out = jax.lax.map(one, (s.yL, s.yR))
     if weighted:
         z, zw, cLm, n_in, ok, vnext, m_le_v = out
-        order = jnp.argsort(z, axis=-1)
-        zs = jnp.take_along_axis(z, order, axis=-1)
-        zws = jnp.take_along_axis(zw, order, axis=-1)
-        m_lt_max = bc(_psum(jnp.sum(
-            jnp.where(x_local < jnp.max(xmax), wl, 0), dtype=mdt), axes_t))
+        with jax.named_scope("sel.sort"):
+            order = jnp.argsort(z, axis=-1)
+            zs = jnp.take_along_axis(z, order, axis=-1)
+            zws = jnp.take_along_axis(zw, order, axis=-1)
+        with jax.named_scope("sel.probe"):
+            m_lt_max = bc(_psum(jnp.sum(
+                jnp.where(x_local < jnp.max(xmax), wl, 0), dtype=mdt),
+                axes_t))
     else:
         z, cLm, n_in, ok, vnext, m_le_v = out
-        zs = jnp.sort(z, axis=-1)
+        with jax.named_scope("sel.sort"):
+            zs = jnp.sort(z, axis=-1)
         zws = None
-        m_lt_max = bc(_psum(jnp.sum(x_local < jnp.max(xmax),
-                                    dtype=jnp.int32), axes_t))
+        with jax.named_scope("sel.probe"):
+            m_lt_max = bc(_psum(jnp.sum(x_local < jnp.max(xmax),
+                                        dtype=jnp.int32), axes_t))
     gcap = zs.shape[-1]
     # a per-shard buffer overflow must fail the sort path even when the
     # GLOBAL count fits the gathered width (survivors were dropped locally)

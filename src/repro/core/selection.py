@@ -104,6 +104,16 @@ exactly, so the row invariants transfer and the compaction/tie logic runs on
 untransformed data.  Exact-hit certificates do NOT survive the fp roundtrip
 (F is not injective in fp): they are dropped per row and re-derived by the
 original-space finalize.
+
+Phase names: every engine phase runs under one ``jax.named_scope``, the
+same on the local and the distributed path, so each compiled op's
+``op_name`` metadata names its phase (metadata only: no op, no host work):
+``sel.seed`` (extreme/mean stats and the analytic bracket seed),
+``sel.sweep`` (the bracket loop: edges, data pass, prefix measures,
+narrowing, psum rounds), ``sel.compact`` (survivor mask, rank cumsum and
+search, gathers), ``sel.probe`` (certificate passes: ``cL``, ``vnext``,
+``m_le_v``, ``m_lt_max``) and ``sel.sort`` (the cap-buffer sort and the
+answer/status cascade).  The scopes never nest.
 """
 from __future__ import annotations
 
@@ -323,6 +333,7 @@ def _live(s: BatchState, cap):
     return (~s.found_exact) & (s.cleR - s.cleL > cap) & (s.yR > s.yL)
 
 
+@jax.named_scope("sel.seed")
 def _seed_state(ev: Evaluator, found0, t0):
     """Shared loop seed: analytic bracket/cut init from one stats pass.
 
@@ -461,7 +472,8 @@ def bracket_loop_batched(
             tp=jnp.where(lv, t, s.tp), fp=jnp.where(lv, fg.f, s.fp),
         )
 
-    return jax.lax.while_loop(cond, body, s0), xmin, xmax
+    with jax.named_scope("sel.sweep"):
+        return jax.lax.while_loop(cond, body, s0), xmin, xmax
 
 
 def binned_descent_step(cum, edges, yL, yR, kk):
@@ -791,7 +803,8 @@ def binned_loop_batched(
         )
         return s, stalled | stall_n
 
-    s, _ = jax.lax.while_loop(cond, body, (s0, stalled0))
+    with jax.named_scope("sel.sweep"):
+        s, _ = jax.lax.while_loop(cond, body, (s0, stalled0))
     return s, xmin, xmax
 
 
@@ -808,14 +821,16 @@ def _run_bracket_phase(ev, method, maxit, cap, nbins, prior=None):
                                 prior=prior)
 
 
+@jax.named_scope("sel.compact")
 def rank_compact(mask_in, cap: int, cols):
     """First-``cap`` survivors of a 1-D mask by RANK GATHER.
 
     The paper's ``copy_if`` as a static-shape gather: ``pos`` is each
     element's inclusive survivor rank (a cumsum of the mask), so the i-th
-    survivor's index is ``searchsorted(pos, i + 1)`` — O(cap log n) cheap
+    survivor's index is ``searchsorted(pos, i + 1)`` — O(cap log n)
     gathers where a full-length scatter lowers to an O(n) serialized loop
-    on XLA:CPU (~20x the whole finalize at 1M, see BENCH_selection.json).
+    on XLA:CPU.  Runs under the ``sel.compact`` scope, whose device time
+    the benchmark reads as ``finalize.compact_ms_per_call``.
     ``cols`` is a sequence of ``(values, pad)`` pairs gathered at the same
     survivor indices (aligned buffers; ``pad`` fills slots past the last
     survivor).  Returns ``(buffers, n_in)``.  Shared by the local finalize
@@ -849,21 +864,26 @@ def _compact_interval(x, w, yL, yR, cap):
     masses are unaffected).
     """
     big = jnp.asarray(jnp.inf, x.dtype)
-    mask_in = (x > yL) & (x <= yR)
-    cL = jnp.sum(x <= yL, dtype=jnp.int32)
-    vnext = jnp.min(jnp.where(x > yL, x, big))
+    with jax.named_scope("sel.compact"):
+        mask_in = (x > yL) & (x <= yR)
+    with jax.named_scope("sel.probe"):
+        cL = jnp.sum(x <= yL, dtype=jnp.int32)
+        vnext = jnp.min(jnp.where(x > yL, x, big))
     if w is None:
         (z,), n_in = rank_compact(mask_in, cap, [(x, big)])
-        m_le_v = jnp.sum(x <= vnext, dtype=jnp.int32)
+        with jax.named_scope("sel.probe"):
+            m_le_v = jnp.sum(x <= vnext, dtype=jnp.int32)
         return z, None, cL, n_in, vnext, m_le_v
     dtw = w.dtype
     (z, zw), n_in = rank_compact(mask_in, cap,
                                  [(x, big), (w, jnp.zeros((), dtw))])
-    cLw = jnp.sum(jnp.where(x <= yL, w, 0), dtype=dtw)
-    w_le_v = jnp.sum(jnp.where(x <= vnext, w, 0), dtype=dtw)
+    with jax.named_scope("sel.probe"):
+        cLw = jnp.sum(jnp.where(x <= yL, w, 0), dtype=dtw)
+        w_le_v = jnp.sum(jnp.where(x <= vnext, w, 0), dtype=dtw)
     return z, zw, cLw, n_in, vnext, w_le_v
 
 
+@jax.named_scope("sel.sort")
 def _assemble_answers(kk, s: BatchState, cap, zs, zws, cLm, n_in, vnext,
                       m_le_v, m_lt_max, xmin, xmax) -> SelectResult:
     """Per-problem answer/status cascade from compacted buffers + measures.
@@ -946,18 +966,22 @@ def _finalize_rows(x, kk, s: BatchState, cap, xmin, xmax,
         z, _, cLm, n_in, vnext, m_le_v = jax.vmap(
             lambda xi, lo, hi: _compact_interval(xi, None, lo, hi, cap)
         )(x, s.yL, s.yR)
-        zs = jnp.sort(z, axis=-1)
+        with jax.named_scope("sel.sort"):
+            zs = jnp.sort(z, axis=-1)
         zws = None
-        m_lt_max = jnp.sum(x < xmax[:, None], axis=1, dtype=jnp.int32)
+        with jax.named_scope("sel.probe"):
+            m_lt_max = jnp.sum(x < xmax[:, None], axis=1, dtype=jnp.int32)
     else:
         z, zw, cLm, n_in, vnext, m_le_v = jax.vmap(
             lambda xi, wi, lo, hi: _compact_interval(xi, wi, lo, hi, cap)
         )(x, w, s.yL, s.yR)
-        order = jnp.argsort(z, axis=-1)
-        zs = jnp.take_along_axis(z, order, axis=-1)
-        zws = jnp.take_along_axis(zw, order, axis=-1)
-        m_lt_max = jnp.sum(jnp.where(x < xmax[:, None], w, 0), axis=1,
-                           dtype=w.dtype)
+        with jax.named_scope("sel.sort"):
+            order = jnp.argsort(z, axis=-1)
+            zs = jnp.take_along_axis(z, order, axis=-1)
+            zws = jnp.take_along_axis(zw, order, axis=-1)
+        with jax.named_scope("sel.probe"):
+            m_lt_max = jnp.sum(jnp.where(x < xmax[:, None], w, 0), axis=1,
+                               dtype=w.dtype)
     return _assemble_answers(kk, s, cap, zs, zws, cLm, n_in, vnext, m_le_v,
                              m_lt_max, xmin, xmax)
 
@@ -976,22 +1000,26 @@ def _finalize_shared(x, kk, s: BatchState, cap, xmin, xmax,
         z, _, cLm, n_in, vnext, m_le_v = jax.lax.map(
             lambda args: _compact_interval(x, None, args[0], args[1], cap),
             (s.yL, s.yR))
-        zs = jnp.sort(z, axis=-1)
+        with jax.named_scope("sel.sort"):
+            zs = jnp.sort(z, axis=-1)
         zws = None
         # one shared pass: xmin/xmax are (K,) broadcasts of global extremes
-        m_lt_max = jnp.broadcast_to(
-            jnp.sum(x < jnp.max(xmax), dtype=jnp.int32), kk.shape)
+        with jax.named_scope("sel.probe"):
+            m_lt_max = jnp.broadcast_to(
+                jnp.sum(x < jnp.max(xmax), dtype=jnp.int32), kk.shape)
     else:
         w = w.reshape(-1)
         z, zw, cLm, n_in, vnext, m_le_v = jax.lax.map(
             lambda args: _compact_interval(x, w, args[0], args[1], cap),
             (s.yL, s.yR))
-        order = jnp.argsort(z, axis=-1)
-        zs = jnp.take_along_axis(z, order, axis=-1)
-        zws = jnp.take_along_axis(zw, order, axis=-1)
-        m_lt_max = jnp.broadcast_to(
-            jnp.sum(jnp.where(x < jnp.max(xmax), w, 0), dtype=w.dtype),
-            kk.shape)
+        with jax.named_scope("sel.sort"):
+            order = jnp.argsort(z, axis=-1)
+            zs = jnp.take_along_axis(z, order, axis=-1)
+            zws = jnp.take_along_axis(zw, order, axis=-1)
+        with jax.named_scope("sel.probe"):
+            m_lt_max = jnp.broadcast_to(
+                jnp.sum(jnp.where(x < jnp.max(xmax), w, 0), dtype=w.dtype),
+                kk.shape)
     return _assemble_answers(kk, s, cap, zs, zws, cLm, n_in, vnext, m_le_v,
                              m_lt_max, xmin, xmax)
 
@@ -1330,17 +1358,21 @@ def _finalize_segmented(x, seg, kk, s: BatchState, cap, xmin,
     def one(args):
         sid, lo, hi, xm = args
         inseg = seg == sid
-        mask_in = inseg & (x > lo) & (x <= hi)
-        cL = jnp.sum(inseg & (x <= lo), dtype=jnp.int32)
-        vnext = jnp.min(jnp.where(inseg & (x > lo), x, big))
+        with jax.named_scope("sel.compact"):
+            mask_in = inseg & (x > lo) & (x <= hi)
+        with jax.named_scope("sel.probe"):
+            cL = jnp.sum(inseg & (x <= lo), dtype=jnp.int32)
+            vnext = jnp.min(jnp.where(inseg & (x > lo), x, big))
         (z,), n_in = rank_compact(mask_in, cap, [(x, big)])
-        m_le_v = jnp.sum(inseg & (x <= vnext), dtype=jnp.int32)
-        m_lt_max = jnp.sum(inseg & (x < xm), dtype=jnp.int32)
+        with jax.named_scope("sel.probe"):
+            m_le_v = jnp.sum(inseg & (x <= vnext), dtype=jnp.int32)
+            m_lt_max = jnp.sum(inseg & (x < xm), dtype=jnp.int32)
         return z, cL, n_in, vnext, m_le_v, m_lt_max
 
     z, cLm, n_in, vnext, m_le_v, m_lt_max = jax.lax.map(
         one, (sids, s.yL, s.yR, xmax))
-    zs = jnp.sort(z, axis=-1)
+    with jax.named_scope("sel.sort"):
+        zs = jnp.sort(z, axis=-1)
     return _assemble_answers(kk, s, cap, zs, None, cLm, n_in, vnext,
                              m_le_v, m_lt_max, xmin, xmax)
 

@@ -311,6 +311,7 @@ def _fg_call_multi(x, w, y, *, block_rows, interpret):
             jax.ShapeDtypeStruct((nblocks, npiv, LANES), jnp.int32),
         ],
         interpret=interpret,
+        name="fg_multi",
     )(y, *data)
     s = jnp.sum(fsum[..., :nf], axis=0)
     # int32 under global x64 too
@@ -343,6 +344,7 @@ def _fg_call_batched(x, w, y, *, block_rows, interpret):
             jax.ShapeDtypeStruct((bsz, nblocks, 1, LANES), jnp.int32),
         ],
         interpret=interpret,
+        name="fg_batched",
     )(y, *data)
     s = jnp.sum(fsum[:, :, 0, :nf], axis=1)
     # int32 under global x64 too
@@ -406,6 +408,7 @@ def _hist_call_multi(x, w, edges, *, block_rows, interpret,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="histogram_multi",
     )(bounds, *data)
     return _slot_counts(tuple(jnp.sum(o[..., :nslots], axis=0, dtype=o.dtype)
                               for o in outs), want_sums)
@@ -441,6 +444,7 @@ def _hist_call_batched(x, w, edges, *, block_rows, interpret,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="histogram_batched",
     )(bounds, *data)
     return _slot_counts(tuple(jnp.sum(o[:, :, 0, :nslots], axis=1,
                                       dtype=o.dtype) for o in outs),
