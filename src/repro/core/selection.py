@@ -147,6 +147,13 @@ BINNED_MIN_N = 1 << 16
 # take the bin count from the edge array the engine builds.
 DEF_NBINS = 128
 
+# Scalar survivor-buffer bound on the kernel path (``_default_cap``).  The
+# rank search of ``rank_compact`` costs log2(n) dependent gathers a slot
+# (~17 ns each on a TPU v5e): at n = 2^28, 2^19 slots took 245 ms of a
+# 383 ms median and 4096 take 1.9 ms for 0.76 more 28 ms sweeps; 4096 was
+# the fastest of 2^12..2^15 there (PERF.md, the cap calibration).
+KERNEL_CAP = 4096
+
 # jnp-path default: the verified-arithmetic histogram's factored one-hot
 # reduction scales with the slot count, and a 16-bin sweep (4 bisection
 # equivalents) already resolves 1M -> cap in 2 sweeps — the CPU-measured
@@ -1024,9 +1031,20 @@ def _finalize_shared(x, kk, s: BatchState, cap, xmin, xmax,
                              m_lt_max, xmin, xmax)
 
 
-def _default_cap(n: int) -> int:
-    # generous: >= 2 * sqrt-ish growth, bounded; paper observed |z| ~ 1-5% n.
-    return int(min(max(4096, n // 64), 1 << 19))
+def _default_cap(n: int, backend: Optional[str] = None) -> int:
+    """Scalar survivor-buffer size: the loop sweeps until at most ``cap``
+    elements stay in the bracket, then compacts them into ``cap`` slots.
+
+    jnp path: generous, ``n // 64`` within ``[4096, 2^19]`` (a binary
+    search is cheap there and a sweep costs 2-4 fused passes).  Kernel
+    path: at most ``KERNEL_CAP``, since each slot costs the rank search
+    ``log2(n)`` dependent gathers over the n-long rank array, far more
+    than the extra sweep a small buffer needs (see ``KERNEL_CAP``).
+    """
+    cap = min(max(4096, n // 64), 1 << 19)
+    if _kernel_path(backend):
+        cap = min(cap, KERNEL_CAP)
+    return int(cap)
 
 
 def _default_cap_rows(n: int) -> int:
@@ -1205,7 +1223,9 @@ def order_statistic(
     """
     x = x.reshape(-1)
     if cap is None:
-        cap = _default_cap(x.size)  # scalar policy: one generous buffer
+        # scalar policy: one buffer, generous on the jnp path, small on the
+        # kernel path where each slot's rank search costs log2(n) gathers
+        cap = _default_cap(x.size, backend)
     res = select_rows(
         x[None, :], jnp.asarray(k, jnp.int32).reshape(1),
         method=method, maxit=maxit, cap=cap, transform=transform,
@@ -1605,7 +1625,9 @@ def weighted_order_statistic(
     """
     x = x.reshape(-1)
     if cap is None:
-        cap = _default_cap(x.size)  # scalar policy: one generous buffer
+        # scalar policy: one buffer, generous on the jnp path, small on the
+        # kernel path where each slot's rank search costs log2(n) gathers
+        cap = _default_cap(x.size, backend)
     res = weighted_select_rows(
         x[None, :], jnp.asarray(w).reshape(1, -1),
         jnp.asarray(wk).reshape(1),

@@ -115,6 +115,60 @@ def test_ties_heavier_than_cap():
                           selection.HYBRID_SORT)
 
 
+# the scalar default cap of the jnp path, n -> cap
+JNP_SCALAR_CAP = {1: 4096, 1000: 4096, 1 << 16: 4096, 1 << 18: 4096,
+                  1 << 19: 8192, 1 << 20: 16384, 1 << 22: 65536,
+                  1 << 24: 262144, 1 << 25: 524288, 1 << 28: 524288,
+                  1 << 30: 524288}
+
+
+@pytest.mark.parametrize("n", sorted(JNP_SCALAR_CAP))
+def test_default_cap_by_path(n):
+    """The jnp path keeps its generous buffer; the kernel path bounds it by
+    KERNEL_CAP from n = 2^19 up and agrees with the jnp path below."""
+    assert selection._default_cap(n) == JNP_SCALAR_CAP[n]
+    assert selection._default_cap(n, "jnp") == JNP_SCALAR_CAP[n]
+    for backend in ("pallas", "pallas_interpret"):
+        got = selection._default_cap(n, backend)
+        if n <= 1 << 18:
+            assert got == JNP_SCALAR_CAP[n]
+        else:
+            assert got == selection.KERNEL_CAP == 4096
+
+
+def _small_cap_cases(n):
+    rng = np.random.default_rng(14)
+    cases = paper_distributions(rng, n)
+    m = (n - n // 10) // 2
+    tie = np.concatenate([rng.random(m), np.full(n - 2 * m, 1.5),
+                          rng.random(m) + 2.0])  # the median in a 10% tie
+    rng.shuffle(tie)
+    cases["tie10_at_median"] = tie
+    cases["three_values"] = rng.choice([-1.0, 0.25, 7.0], n)
+    return {k: v.astype(np.float32) for k, v in cases.items()}
+
+
+@pytest.mark.parametrize("name", [
+    "uniform", "normal", "halfnormal", "beta25", "mix1", "mix2", "mix3",
+    "mix4", "mix5", "tie10_at_median", "three_values",
+])
+def test_kernel_path_small_cap_exact(name):
+    """The kernel path's regime: sweeps run until at most a small cap of
+    survivors is left (or a tie or an ulp-collapse certifies), and every
+    answer is still np.partition's, bit for bit."""
+    n, cap = 1 << 16, 64
+    x = _small_cap_cases(n)[name]
+    want = exact_kth(x, (n + 1) // 2)
+    small = selection.median(jnp.asarray(x), backend="pallas_interpret",
+                             cap=cap)
+    wide = selection.median(jnp.asarray(x), backend="pallas_interpret",
+                            cap=4096)
+    for res in (small, wide):
+        assert int(res.status) != selection.NOT_CONVERGED
+        assert np.float32(res.value).view(np.uint32) == want.view(np.uint32)
+    assert int(small.iters) >= int(wide.iters)
+
+
 def test_integer_valued_data_all_ties():
     rng = np.random.default_rng(6)
     x = rng.integers(0, 7, 50_001).astype(np.float32)
