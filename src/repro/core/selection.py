@@ -844,6 +844,12 @@ def rank_compact(mask_in, cap: int, cols):
     (:func:`_compact_interval`) and the distributed per-shard finalize —
     keep it the single implementation.
     """
+    return _rank_gather(mask_in, cap, cols)
+
+
+def _rank_gather(mask_in, cap: int, cols):
+    """:func:`rank_compact` without its scope, for callers already inside
+    ``sel.compact`` (phases never nest)."""
     n_in = jnp.sum(mask_in, dtype=jnp.int32)
     pos = jnp.cumsum(mask_in.astype(jnp.int32))
     idx = jnp.minimum(
@@ -854,40 +860,50 @@ def rank_compact(mask_in, cap: int, cols):
     return [jnp.where(have, v[idx], pad) for v, pad in cols], n_in
 
 
+def _bracket_probes(x, w, yL):
+    """The certificate probes of one problem's final bracket (1-D ``x``):
+    ``cLm = measure(x <= yL)``, the next distinct value ``vnext`` above
+    ``yL``, and ``measure(x <= vnext)`` (the tie fallback's check).
+    ``w=None`` is the counting leg (int32 counts); unscoped, the caller
+    names the phase."""
+    big = jnp.asarray(jnp.inf, x.dtype)
+    if w is None:
+        measure = lambda m: jnp.sum(m, dtype=jnp.int32)
+    else:
+        measure = lambda m: jnp.sum(jnp.where(m, w, 0), dtype=w.dtype)
+    cLm = measure(x <= yL)
+    vnext = jnp.min(jnp.where(x > yL, x, big))
+    return cLm, vnext, measure(x <= vnext)
+
+
+def _bracket_survivors(x, w, yL, yR, cap):
+    """The open bracket ``(yL, yR]`` of one problem (1-D ``x``) gathered
+    into ``(cap,)`` buffers: values (+inf pad) and, on the weighted leg,
+    the aligned weights (0 pad, so sorted prefix masses are unaffected).
+    Returns ``(z, zw or None, n_in)``; unscoped, the caller names the
+    phase."""
+    cols = [(x, jnp.asarray(jnp.inf, x.dtype))]
+    if w is not None:
+        cols.append((w, jnp.zeros((), w.dtype)))
+    bufs, n_in = _rank_gather((x > yL) & (x <= yR), cap, cols)
+    return bufs[0], (bufs[1] if w is not None else None), n_in
+
+
 def _compact_interval(x, w, yL, yR, cap):
     """ONE problem's phase-2 survivor compaction + fallback probes (1-D x).
 
-    The open pivot interval ``(yL, yR]`` lands in a ``(cap,)`` buffer via
-    :func:`rank_compact` (first ``cap`` survivors in data order, +inf
-    pad), alongside the measure certificates the answer assembly needs —
-    ``cLm = measure(x <= yL)``, the in-bracket count, the next distinct
-    value above ``yL`` and its inclusive measure (tie fallback
-    verification).  Everything downstream is O(cap), not O(n).
-
+    The open pivot interval ``(yL, yR]`` lands in a ``(cap,)`` buffer
+    (first ``cap`` survivors in data order, :func:`_bracket_survivors`),
+    alongside the measure certificates the answer assembly needs
+    (:func:`_bracket_probes`).  Everything downstream is O(cap), not O(n).
     ``w=None`` is the counting leg: the measures are the int32 counts and
-    the weight buffer comes back ``None`` (no weight reads).  With
-    weights, the (value, weight) PAIRS land in aligned buffers via the
-    same rank indices (pad values +inf, pad weights 0 so sorted prefix
-    masses are unaffected).
+    the weight buffer comes back ``None`` (no weight reads).
     """
-    big = jnp.asarray(jnp.inf, x.dtype)
     with jax.named_scope("sel.compact"):
-        mask_in = (x > yL) & (x <= yR)
+        z, zw, n_in = _bracket_survivors(x, w, yL, yR, cap)
     with jax.named_scope("sel.probe"):
-        cL = jnp.sum(x <= yL, dtype=jnp.int32)
-        vnext = jnp.min(jnp.where(x > yL, x, big))
-    if w is None:
-        (z,), n_in = rank_compact(mask_in, cap, [(x, big)])
-        with jax.named_scope("sel.probe"):
-            m_le_v = jnp.sum(x <= vnext, dtype=jnp.int32)
-        return z, None, cL, n_in, vnext, m_le_v
-    dtw = w.dtype
-    (z, zw), n_in = rank_compact(mask_in, cap,
-                                 [(x, big), (w, jnp.zeros((), dtw))])
-    with jax.named_scope("sel.probe"):
-        cLw = jnp.sum(jnp.where(x <= yL, w, 0), dtype=dtw)
-        w_le_v = jnp.sum(jnp.where(x <= vnext, w, 0), dtype=dtw)
-    return z, zw, cLw, n_in, vnext, w_le_v
+        cLm, vnext, m_le_v = _bracket_probes(x, w, yL)
+    return z, zw, cLm, n_in, vnext, m_le_v
 
 
 @jax.named_scope("sel.sort")
@@ -1000,33 +1016,37 @@ def _finalize_shared(x, kk, s: BatchState, cap, xmin, xmax,
     The compaction runs per pivot against the ONE ``(n,)`` array (pair on
     the weighted leg), sequential ``lax.map`` over the K brackets, so peak
     memory stays O(n + K*cap) — the hot iterations (multi-bracket kernel)
-    and the finalize both avoid materializing ``(K, n)``.
+    and the finalize both avoid materializing ``(K, n)``.  The map runs
+    inside ``sel.compact`` as a whole, so that its own loop ops (the
+    counter, the stacking of the K results) are named too; phases never
+    nest, so its body is the unscoped helpers, and the per-pivot probes,
+    which XLA fuses into the survivor mask's pass, count as compaction.
     """
     x = x.reshape(-1)
-    if w is None:
-        z, _, cLm, n_in, vnext, m_le_v = jax.lax.map(
-            lambda args: _compact_interval(x, None, args[0], args[1], cap),
-            (s.yL, s.yR))
-        with jax.named_scope("sel.sort"):
-            zs = jnp.sort(z, axis=-1)
-        zws = None
-        # one shared pass: xmin/xmax are (K,) broadcasts of global extremes
-        with jax.named_scope("sel.probe"):
-            m_lt_max = jnp.broadcast_to(
-                jnp.sum(x < jnp.max(xmax), dtype=jnp.int32), kk.shape)
-    else:
+    if w is not None:
         w = w.reshape(-1)
-        z, zw, cLm, n_in, vnext, m_le_v = jax.lax.map(
-            lambda args: _compact_interval(x, w, args[0], args[1], cap),
-            (s.yL, s.yR))
-        with jax.named_scope("sel.sort"):
+
+    def one(b):
+        return (_bracket_survivors(x, w, b[0], b[1], cap)
+                + _bracket_probes(x, w, b[0]))
+
+    with jax.named_scope("sel.compact"):
+        z, zw, n_in, cLm, vnext, m_le_v = jax.lax.map(one, (s.yL, s.yR))
+    with jax.named_scope("sel.probe"):
+        # one shared pass: xmin/xmax are (K,) broadcasts of global extremes
+        below_max = x < jnp.max(xmax)
+        if w is None:
+            m_lt_max = jnp.sum(below_max, dtype=jnp.int32)
+        else:
+            m_lt_max = jnp.sum(jnp.where(below_max, w, 0), dtype=w.dtype)
+        m_lt_max = jnp.broadcast_to(m_lt_max, kk.shape)
+    with jax.named_scope("sel.sort"):
+        if w is None:
+            zs, zws = jnp.sort(z, axis=-1), None
+        else:
             order = jnp.argsort(z, axis=-1)
             zs = jnp.take_along_axis(z, order, axis=-1)
             zws = jnp.take_along_axis(zw, order, axis=-1)
-        with jax.named_scope("sel.probe"):
-            m_lt_max = jnp.broadcast_to(
-                jnp.sum(jnp.where(x < jnp.max(xmax), w, 0), dtype=w.dtype),
-                kk.shape)
     return _assemble_answers(kk, s, cap, zs, zws, cLm, n_in, vnext, m_le_v,
                              m_lt_max, xmin, xmax)
 
